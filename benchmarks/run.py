@@ -38,6 +38,9 @@ def main() -> None:
     unknown = selected - set(SECTIONS)
     if unknown:
         ap.error(f"unknown sections: {', '.join(sorted(unknown))}")
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     rows = []
 

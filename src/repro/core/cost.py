@@ -56,15 +56,22 @@ def pool_costs(
     return np.asarray(out, np.float64)
 
 
-def normalize_costs(costs: np.ndarray, budget: float, buckets: int = 256):
+def normalize_costs(costs: np.ndarray, budget, buckets: int = 256):
     """Discretize FLOPs costs into integer knapsack weights.
 
     The paper's Algorithm 1 indexes the DP table by integer cost; real FLOP
     counts are ~1e12, so we quantize weights to ``buckets`` levels of the
     budget.  Ceil-rounding keeps the constraint conservative (never exceeds
-    the true budget).  Returns (int_costs, int_budget).
+    the true budget).  ``budget`` is a scalar or broadcasts against
+    ``costs`` (one budget per query row, ``[Q, 1]``).  The arithmetic is
+    float64 on the host, so the weights do not depend on how a backend
+    rounds float32 division: on tie-heavy inputs ``costs / scale`` is often
+    an exact integer, and a float32 quotient lands on either side of the
+    ceil.  A zero budget (an all-zero cost row) makes every member free.
+    Returns (int_costs, int_budget).
     """
-    scale = budget / buckets
+    scale = np.asarray(budget, np.float64) / buckets
+    scale = np.where(scale > 0, scale, 1.0)
     int_costs = np.ceil(np.asarray(costs, np.float64) / scale).astype(np.int64)
     int_costs = np.maximum(int_costs, 1)
     return int_costs, int(buckets)

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.cost import normalize_costs
 from repro.core.knapsack import knapsack_select, shift_scores
 
 
@@ -45,18 +46,17 @@ def select_under_budget(
     ``impl`` picks the bitmask-DP backend: ``"lax"`` (batched jittable
     loop, the serving default) or ``"pallas"`` (the VMEM-resident TPU
     kernel in ``repro.kernels.knapsack``).  Both produce identical
-    selections."""
+    selections.  Costs are bucketed on the host in float64
+    (:func:`repro.core.cost.normalize_costs`), so every backend solves the
+    same integer knapsack; the DP itself runs on the device."""
     quality = jnp.asarray(quality, jnp.float32)
-    # FLOP counts up to ~1e15 are exactly representable enough for bucketing
-    costs_flops = jnp.asarray(costs_flops, jnp.float32)
     profits, _ = shift_scores(quality)
-    budget_flops = eps.fraction * jnp.sum(costs_flops, axis=1, keepdims=True)  # [Q,1]
-    scale = budget_flops / eps.buckets
-    # a zero-cost row (empty/degenerate pool costs) would make scale 0 and
-    # NaN the whole mask; every member is free there, so any scale works
-    scale = jnp.where(scale > 0, scale, 1.0)
-    int_costs = jnp.ceil(costs_flops / scale).astype(jnp.int32)
-    int_costs = jnp.maximum(int_costs, 1)
+    costs_flops = np.asarray(costs_flops, np.float64)
+    budget_flops = eps.fraction * np.sum(costs_flops, axis=1, keepdims=True)  # [Q,1]
+    int_costs, _ = normalize_costs(costs_flops, budget_flops, eps.buckets)
+    # any weight above the budget is equally infeasible; the cap keeps a
+    # tiny ε from overflowing int32
+    int_costs = jnp.asarray(np.minimum(int_costs, eps.buckets + 1), jnp.int32)
     if impl == "pallas":
         from repro.kernels.knapsack import knapsack_select_pallas
 

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.models.config import ModelConfig
 from repro.models.layers import (
     apply_norm,
+    declared_precision,
     dense_init,
     embed_init,
     huber_loss,
@@ -112,6 +113,7 @@ class QualityPredictor:
         out = jnp.einsum("bhij,bjhk->bihk", probs, v.astype(f32)).astype(x.dtype)
         return jnp.einsum("bshk,hkd->bsd", out, p_l["wo"])
 
+    @declared_precision
     def encode(self, params: dict, tokens: jax.Array) -> jax.Array:
         """tokens: [B, S] -> hidden [B, S, D] (token 0 is CLS)."""
         ecfg = self.cfg.encoder
@@ -128,6 +130,7 @@ class QualityPredictor:
         return apply_norm(params["final_norm"], x, ecfg.norm_eps)
 
     # ------------------------------------------------------------------
+    @declared_precision
     def apply(
         self,
         params: dict,

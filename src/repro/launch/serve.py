@@ -34,6 +34,7 @@ from repro.data import (
 )
 from repro.models import build_model
 from repro.optim import AdamW
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import cluster_host_devices
 from repro.serve import (
     AdmissionControl,
@@ -190,6 +191,7 @@ def main():
                     help="max rows per prefill call on the streaming path "
                          "(bounds how long a prompt burst can stall joins)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     recs, scorer, scorer_p, fuser, fuser_p, predictor, pred_p = build_stack(
         args.train_steps, args.seed

@@ -21,6 +21,7 @@ from repro.models.layers import (
     apply_norm,
     chunked_ce_from_hidden,
     cross_entropy,
+    declared_precision,
     dense_init,
     embed_init,
     embed_tokens,
@@ -78,6 +79,7 @@ class EncDecLM:
         return params
 
     # ------------------------------------------------------------------
+    @declared_precision
     def encode(
         self,
         params: dict,
@@ -161,6 +163,7 @@ class EncDecLM:
         return lm_logits(params["lm_head"], x, transpose=False)
 
     # ------------------------------------------------------------------
+    @declared_precision
     def forward(self, params, dec_tokens, enc_frontend=None, enc_tokens=None):
         enc_out = self.encode(params, enc_frontend, enc_tokens)
         b, s = dec_tokens.shape
@@ -169,6 +172,7 @@ class EncDecLM:
         x, _ = self._dec_stack(params, x, positions, enc_out=enc_out)
         return self._head(params, x)
 
+    @declared_precision
     def loss(self, params, batch, remat: bool = False):
         """Fused chunked head+CE — full [B, S, V] logits never materialize."""
         cfg = self.cfg
@@ -207,6 +211,7 @@ class EncDecLM:
             "cv": jnp.zeros((l, batch, se, h, hd), dtype),
         }
 
+    @declared_precision
     def prefill(self, params, dec_tokens, cache, enc_frontend=None, enc_tokens=None):
         enc_out = self.encode(params, enc_frontend, enc_tokens)
         b, s = dec_tokens.shape
@@ -215,6 +220,7 @@ class EncDecLM:
         x, new_cache = self._dec_stack(params, x, positions, enc_out=enc_out, cache=cache)
         return self._head(params, x)[:, -1:], new_cache
 
+    @declared_precision
     def decode_step(self, params, token, pos, cache):
         x = embed_tokens(params["embed"], token).astype(self.dtype)
         x, new_cache = self._dec_stack(params, x, None, cache=cache, pos=pos)

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -9,6 +10,34 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.sharding import logical_constraint
+
+# ---------------------------------------------------------------------------
+# Matmul precision
+# ---------------------------------------------------------------------------
+
+
+def declared_precision(method):
+    """Run a model method's matmuls at the precision its dtype declares.
+
+    A TPU's default precision rounds every float32 matmul operand to
+    bfloat16 for a single MXU pass, so a float32 model would compute at
+    bf16 precision, and its greedy text would depend on the batch and cache
+    shapes a program was compiled for (the rounding follows the tiling).
+    Methods of a float32 model therefore trace under "highest" precision
+    unless the caller set one with ``jax.default_matmul_precision``; other
+    dtypes are left alone.  The CPU computes float32 matmuls in float32
+    either way."""
+
+    @functools.wraps(method)
+    def wrapped(self, *args, **kwargs):
+        if (self.dtype != jnp.float32
+                or jax.config.jax_default_matmul_precision is not None):
+            return method(self, *args, **kwargs)
+        with jax.default_matmul_precision("highest"):
+            return method(self, *args, **kwargs)
+
+    return wrapped
+
 
 # ---------------------------------------------------------------------------
 # Initialization

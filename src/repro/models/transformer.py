@@ -23,6 +23,7 @@ from repro.models.layers import (
     apply_norm,
     chunked_ce_from_hidden,
     cross_entropy,
+    declared_precision,
     dense_init,
     embed_tokens,
     init_embedding,
@@ -146,6 +147,7 @@ class DecoderLM:
     # ------------------------------------------------------------------
     # Full-sequence forward (training / prefill)
     # ------------------------------------------------------------------
+    @declared_precision
     def forward(
         self,
         params: dict,
@@ -202,10 +204,12 @@ class DecoderLM:
     # ------------------------------------------------------------------
     # Serving steps
     # ------------------------------------------------------------------
+    @declared_precision
     def prefill(self, params, tokens, cache, frontend=None, positions=None):
         logits, new_cache, _, _ = self.forward(params, tokens, frontend, cache, positions=positions)
         return logits[:, -1:], new_cache
 
+    @declared_precision
     def decode_step(self, params, token, pos, cache):
         """token: [B, 1] int32; pos: [B] absolute positions."""
         cfg = self.cfg
@@ -240,6 +244,7 @@ class DecoderLM:
             return h, params["embed"], True
         return h, params["lm_head"], False
 
+    @declared_precision
     def loss(self, params, batch, remat: bool = False):
         """batch: {tokens [B,S], loss_mask [B,S] opt, frontend opt}.
 

@@ -34,16 +34,21 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import threading
 import time
-from typing import Dict, List, Protocol, Sequence, Tuple, Union, runtime_checkable
+from typing import (Dict, List, Optional, Protocol, Sequence, Tuple, Union,
+                    runtime_checkable)
 
+import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.data.mixinstruct import PoolMemberSpec, Record, member_response
 from repro.data.tokenizer import TOKENIZER
 from repro.models.transformer import DecoderLM
 from repro.serve.dispatch import BucketLadder, DecoderGenerateDispatcher
 from repro.serve.generate import greedy_generate
+from repro.sharding.api import current_rules
 
 MaxNewTokens = Union[int, Sequence[int]]
 
@@ -206,10 +211,11 @@ class FailureInjector:
         return self.inner.generate(member_idx, records, max_new_tokens)
 
     # optional-protocol hooks forward to the wrapped backend
-    def warm(self, shapes: Sequence) -> None:
+    def warm(self, shapes: Sequence,
+             members: Optional[Sequence[int]] = None) -> None:
         warm = getattr(self.inner, "warm", None)
         if callable(warm):
-            warm(shapes)
+            warm(shapes, members=members)
 
     def compiles(self) -> int:
         compiles = getattr(self.inner, "compiles", None)
@@ -246,30 +252,46 @@ class LiveLMBackend:
     """Live JAX LMs: prompt = ``<bos> query <sep>``, greedy decode.
 
     ``fast=True`` routes generation through one
-    :class:`~repro.serve.dispatch.DecoderGenerateDispatcher` per member:
-    micro-batches pad up to the bucket ladder, each bucket compiles once,
-    and the decode cache is donated back to the same buffers call after
-    call.  ``fast=False`` keeps the ad-hoc jit path (one compile per
-    distinct shape)."""
+    :class:`~repro.serve.dispatch.DecoderGenerateDispatcher` per member and
+    host: micro-batches pad up to the bucket ladder, each bucket compiles
+    once, and the decode cache is donated back to the same buffers call
+    after call.  ``fast=False`` keeps the ad-hoc jit path (one compile per
+    distinct shape).
+
+    Under a cluster host's axis rules (installed by
+    :class:`~repro.serve.cluster.ClusterRouter` around each routed call)
+    the member's dispatcher is the one for that host's mesh: its weights
+    and decode caches live on the host's devices, and its programs were
+    traced under that host's rules only, so a trace for one host is never
+    reused on another."""
 
     members: Sequence[LiveMember]
     max_query_len: int = 96
     fast: bool = True
     ladder: BucketLadder = dataclasses.field(default_factory=BucketLadder)
-    _dispatchers: Dict[int, DecoderGenerateDispatcher] = dataclasses.field(
-        default_factory=dict, repr=False
-    )
+    _dispatchers: Dict[Tuple[int, object], DecoderGenerateDispatcher] = (
+        dataclasses.field(default_factory=dict, repr=False))
+    _lock: threading.Lock = dataclasses.field(
+        default_factory=threading.Lock, repr=False)
 
     def num_members(self) -> int:
         return len(self.members)
 
     def _dispatcher(self, member_idx: int) -> DecoderGenerateDispatcher:
-        d = self._dispatchers.get(member_idx)
-        if d is None:
-            lm = self.members[member_idx]
-            d = self._dispatchers[member_idx] = DecoderGenerateDispatcher(
-                lm.model, lm.params, ladder=self.ladder
-            )
+        rules = current_rules()
+        mesh = None if rules is None else rules.mesh
+        with self._lock:
+            d = self._dispatchers.get((member_idx, mesh))
+            if d is None:
+                lm = self.members[member_idx]
+                placement = (None if mesh is None
+                             else NamedSharding(mesh, PartitionSpec()))
+                params = (lm.params if placement is None
+                          else jax.device_put(lm.params, placement))
+                d = self._dispatchers[(member_idx, mesh)] = (
+                    DecoderGenerateDispatcher(lm.model, params,
+                                              ladder=self.ladder,
+                                              placement=placement))
         return d
 
     def compiles(self) -> int:
@@ -277,13 +299,17 @@ class LiveLMBackend:
         the dict first: fan-out shards lazily create dispatchers on host
         executor threads, and iterating a dict another thread is
         inserting into raises."""
-        return sum(d.compiles for d in list(self._dispatchers.values()))
+        with self._lock:
+            dispatchers = list(self._dispatchers.values())
+        return sum(d.compiles for d in dispatchers)
 
-    def warm(self, shapes: Sequence) -> None:
-        """Pre-compile the given (batch, max_new) buckets for every member."""
+    def warm(self, shapes: Sequence,
+             members: Optional[Sequence[int]] = None) -> None:
+        """Pre-compile the given (batch, max_new) buckets for ``members``
+        (default: every member) under the axis rules currently installed."""
         if not self.fast:
             return  # the ad-hoc jit path has no buckets to warm
-        for j in range(len(self.members)):
+        for j in range(len(self.members)) if members is None else members:
             self._dispatcher(j).warm(
                 [(b, self.max_query_len, n) for b, n in shapes]
             )

@@ -5,9 +5,11 @@ wrapper: the engine's per-member generation calls arrive here, the
 router resolves the member's *primary* (first alive) replica host from
 the :class:`~repro.serve.cluster.placement.PlacementPlan`, installs that
 host's mesh rules for the duration of the call, and forwards to the
-inner backend — whose per-member jit caches
-(:class:`~repro.serve.dispatch.BucketLadder` buckets) are shared across
-hosts, so routing never costs a recompile.
+inner backend.  A live backend keys each member's compiled buckets and
+decode caches by that host's mesh, so a member's weights and caches sit
+on its host's devices and a program traced under one host's rules never
+runs on another; :meth:`warm` compiles them per host.  On a logical-only
+plan (no devices) every host shares one set.
 
 Fan-out (``fanout=True``) turns the router from a routing table into a
 concurrent executor fabric: one batch's generation calls are *planned*
@@ -263,14 +265,18 @@ class ClusterRouter:
                 f"injected host failure: host {host}, dispatch {k}"))
         return k
 
+    def _host_rules(self, member_idx: int, host: int):
+        """Context installing the host's mesh rules for this member (a
+        no-op on a logical-only plan)."""
+        rules = self.plan.member_rules(member_idx, host=host)
+        return axis_rules(rules) if rules is not None else contextlib.nullcontext()
+
     def _run(self, host: int, member_idx: int, records: Sequence,
              max_new_tokens: MaxNewTokens) -> List[str]:
         """The actual inner generate, under the pinned host's mesh rules."""
-        rules = self.plan.member_rules(member_idx, host=host)
-        ctx = axis_rules(rules) if rules is not None else contextlib.nullcontext()
         token = _CURRENT_HOST.set(host)
         try:
-            with ctx:
+            with self._host_rules(member_idx, host):
                 return self.inner.generate(member_idx, records, max_new_tokens)
         finally:
             _CURRENT_HOST.reset(token)
@@ -550,10 +556,24 @@ class ClusterRouter:
             self._pool.close()
 
     # -- optional protocol hooks forward to the wrapped backend ----------
-    def warm(self, shapes: Sequence) -> None:
+    def warm(self, shapes: Sequence,
+             members: Optional[Sequence[int]] = None) -> None:
+        """Warm each member once per distinct host mesh it is placed on,
+        under that host's rules: a member-level backend keys its compiled
+        programs and caches by host, so warming outside the rules would
+        compile programs no routed call uses."""
         warm = getattr(self.inner, "warm", None)
-        if callable(warm):
-            warm(shapes)
+        if not callable(warm):
+            return
+        for j in range(self.plan.n_members) if members is None else members:
+            meshes = set()
+            for host in self.plan.placements[j].hosts:
+                mesh = self.plan.host_mesh(host)
+                if mesh in meshes:
+                    continue
+                meshes.add(mesh)
+                with self._host_rules(j, host):
+                    warm(shapes, members=(j,))
 
     def compiles(self) -> int:
         compiles = getattr(self.inner, "compiles", None)

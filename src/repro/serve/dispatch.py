@@ -115,8 +115,12 @@ class _BucketedGenerate:
     """Shared machinery: bucket lookup, padding, entry cache, stats."""
 
     def __init__(self, params: dict, pad_id: int, eos_id: int,
-                 ladder: Optional[BucketLadder], donate: Optional[bool]):
+                 ladder: Optional[BucketLadder], donate: Optional[bool],
+                 placement: Optional[jax.sharding.Sharding] = None):
         self.params = params
+        # where the decode caches and token inputs live (None = default
+        # device); params are expected to be placed there already
+        self.placement = placement
         self.pad_id = pad_id
         self.eos_id = eos_id
         self.ladder = ladder or BucketLadder()
@@ -169,6 +173,11 @@ class _BucketedGenerate:
         has no pad masking, so its subclass keeps the length verbatim."""
         return self.ladder.prompt_bucket(s)
 
+    def _place(self, x):
+        if self.placement is None:
+            return jax.tree.map(jnp.asarray, x)
+        return jax.device_put(x, self.placement)
+
     # -- dispatch --------------------------------------------------------
     def _entry(self, bb: int, sb: int, nb: int) -> _Entry:
         key = (bb, sb, nb)
@@ -199,7 +208,7 @@ class _BucketedGenerate:
         with self._call_lock:
             entry = self._entry(bb, sb, nb)
             try:
-                out, entry.cache = entry.fn(self.params, jnp.asarray(padded),
+                out, entry.cache = entry.fn(self.params, self._place(padded),
                                             entry.cache)
             except Exception:
                 # with donation active the cache buffer may already be consumed
@@ -231,8 +240,9 @@ class DecoderGenerateDispatcher(_BucketedGenerate):
     def __init__(self, model: DecoderLM, params: dict,
                  pad_id: int = TOKENIZER.pad_id, eos_id: int = TOKENIZER.eos_id,
                  ladder: Optional[BucketLadder] = None,
-                 donate: Optional[bool] = None):
-        super().__init__(params, pad_id, eos_id, ladder, donate)
+                 donate: Optional[bool] = None,
+                 placement: Optional[jax.sharding.Sharding] = None):
+        super().__init__(params, pad_id, eos_id, ladder, donate, placement)
         self.model = model
 
     def _build(self, bb: int, sb: int, nb: int) -> _Entry:
@@ -247,7 +257,8 @@ class DecoderGenerateDispatcher(_BucketedGenerate):
         return _Entry(fn=fn, cache=self._make_cache(bb, sb, nb))
 
     def _make_cache(self, bb: int, sb: int, nb: int) -> dict:
-        return self.model.init_cache(bb, sb + nb + self.model.cfg.frontend_tokens)
+        return self._place(
+            self.model.init_cache(bb, sb + nb + self.model.cfg.frontend_tokens))
 
     def _direct(self, tokens: np.ndarray, max_new: int) -> np.ndarray:
         from repro.serve.generate import greedy_generate

@@ -1,6 +1,7 @@
 """Per-architecture smoke tests: reduced variant of each assigned arch runs
 one forward + one train step on CPU, asserting shapes and no NaNs."""
 
+import contextlib
 import dataclasses
 
 import jax
@@ -123,3 +124,35 @@ def test_long_context_support_flags():
     dense = configs.get("smollm-360m")
     assert not dense.supports_long_context
     assert dataclasses.replace(dense, sliding_window=8192).supports_long_context
+
+
+@pytest.mark.parametrize("dtype,caller,want_highest", [
+    ("float32", None, True),
+    ("float32", "bfloat16", False),
+    ("bfloat16", None, False),
+])
+def test_float32_models_trace_highest_precision_matmuls(dtype, caller, want_highest):
+    """A float32 model's serving steps trace their matmuls at "highest"
+    precision (a TPU would otherwise take one bf16 pass); a precision the
+    caller set wins, and other dtypes keep the backend default."""
+    from repro.core import build_predictor
+
+    cfg = configs.get("gen-fuser").reduced(dtype=dtype)
+    fuser = build_model(cfg)
+    fp = jax.eval_shape(fuser.init, jax.random.key(0))
+    predictor = build_predictor(4, encoder=configs.get("modi-predictor").reduced(dtype=dtype))
+    pp = jax.eval_shape(predictor.init, jax.random.key(0))
+    cache = jax.eval_shape(lambda: fuser.init_cache(B, 8, enc_seq=S))
+    tok = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    pos = jax.ShapeDtypeStruct((B,), jnp.int32)
+    enc = jax.ShapeDtypeStruct((B, S), jnp.int32)
+    ctx = jax.default_matmul_precision(caller) if caller else contextlib.nullcontext()
+    with ctx:
+        texts = [
+            jax.jit(fuser.prefill).lower(fp, tok, cache, enc_tokens=enc).as_text(),
+            jax.jit(fuser.decode_step).lower(fp, tok, pos, cache).as_text(),
+            jax.jit(predictor.apply).lower(pp, enc).as_text(),
+        ]
+    for text in texts:
+        assert "dot_general" in text
+        assert ("HIGHEST" in text) == want_highest

@@ -81,3 +81,27 @@ def test_collective_parser():
     assert by["all-gather"] == 16 * 128 * 2
     assert by["all-reduce"] == 4 * 4 * 4 + 2 * 4
     assert counts["all-gather"] == 1 and counts["all-reduce"] == 1
+
+
+def test_compile_cache_follows_env_var(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_compile_cache_default_is_fixed_checkout_path(monkeypatch):
+    import pathlib
+
+    from repro.launch import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = compile_cache.compile_cache_dir()
+    assert first == compile_cache.compile_cache_dir()
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert pathlib.Path(first) == root / ".jax_cache"

@@ -709,3 +709,36 @@ def test_diurnal_arrivals_are_deterministic_and_follow_curve():
     period = proc.period
     counts = np.bincount(np.asarray(a) // period)
     assert counts.max() > len(a) / max(len(counts), 1)
+
+
+def test_live_members_compile_and_live_on_their_host():
+    """A live member's weights, decode caches and compiled buckets belong
+    to the host the router placed it on: warming under each host's rules
+    builds exactly one dispatcher per (member, host mesh), every array it
+    holds sits on that host's devices, routed calls reuse it without a
+    compile, and the text equals an unrouted backend's."""
+    from repro.serve import LiveLMBackend, LiveMember
+
+    cfg = configs.get("smollm-360m").reduced(dtype="float32")
+    model = build_model(cfg)
+    members = [LiveMember(DEFAULT_POOL[j], model, model.init(jax.random.key(j)))
+               for j in range(2)]
+    devices = jax.devices()
+    n_hosts = min(2, len(devices))
+    plan = PlacementPlan.round_robin(2, n_hosts, devices=devices[:n_hosts])
+    live = LiveLMBackend(members)
+    router = ClusterRouter(live, plan=plan)
+    router.warm([(1, 4)])
+    meshes = {j: plan.host_mesh(plan.primary_host(j)) for j in range(2)}
+    assert set(live._dispatchers) == {(j, meshes[j]) for j in range(2)}
+    for (j, mesh), d in live._dispatchers.items():
+        want = set(mesh.devices.flat)
+        leaves = jax.tree.leaves(d.params) + [
+            leaf for e in d._entries.values() for leaf in jax.tree.leaves(e.cache)]
+        assert leaves and all(leaf.devices() == want for leaf in leaves)
+    warm_compiles = live.compiles()
+    recs = RECORDS[:1]
+    plain = LiveLMBackend(members)
+    for j in range(2):
+        assert router.generate(j, recs, 4) == plain.generate(j, recs, 4)
+    assert live.compiles() == warm_compiles
