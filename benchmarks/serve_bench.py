@@ -9,7 +9,8 @@ accumulates per PR:
   (first/steady-state batch latency, compile counters), plus a streaming
   probe through the persistent in-flight decode state reporting
   ``ttft_ms`` (median time-to-first-token) against the batch-boundary
-  baseline, ``decode_step_p99_ms``, and the steady-state
+  baseline, ``decode_step_p99_ms`` (from the tracer's
+  ``serve.fuser.step`` spans), and the steady-state
   ``generate_compiles`` gate (must stay 0);
 * ``--scenario steady|bursty|heavy-tail|failure`` — drive the
   deterministic traffic simulator (:mod:`repro.serve.traffic`) through
@@ -38,6 +39,7 @@ from repro.serve import (
     TrafficSimulator,
     preset_scenarios,
     requests_from_records,
+    spans,
 )
 
 
@@ -75,10 +77,10 @@ def run(n_batches: int = 8, batch_size: int = 4, budget: float = 0.2,
     steady = float(np.median(per_batch_s[1:])) if n_batches > 1 else per_batch_s[0]
 
     # --- streaming probe: token-level continuous batching through the
-    # persistent in-flight decode state.  TTFT is wall time from batch
-    # service start to a request's first fused token; the batch-boundary
-    # baseline only surfaces its first token when the whole batch settles,
-    # so its TTFT *is* the steady-state batch latency measured above.
+    # persistent in-flight decode state.  TTFT is wall time from submit to
+    # a request's first fused token; the batch-boundary baseline only
+    # surfaces its first token when the whole batch settles, so its TTFT
+    # is the steady-state batch latency measured above.
     stream_server = _build_server(budget)
     stream_sched = Scheduler(stream_server, max_batch_size=batch_size,
                              stream=True, stream_capacity=batch_size)
@@ -87,7 +89,7 @@ def run(n_batches: int = 8, batch_size: int = 4, budget: float = 0.2,
     fuser.warm(sorted({ladder.batch_bucket(b)
                        for b in range(1, batch_size + 1)}))
     compiles_after_warm = stream_server.generate_compiles()["total"]
-    n_warm_steps = len(fuser.step_wall_s)
+    spans.enable()
     ttft_s = []
     for k in range(n_batches):
         reqs = requests_from_records(records[k * batch_size:(k + 1) * batch_size])
@@ -96,7 +98,9 @@ def run(n_batches: int = 8, batch_size: int = 4, budget: float = 0.2,
         for f in futures:
             f.result()
         ttft_s.extend(f.ttft_s for f in futures if f.ttft_s is not None)
-    step_walls = fuser.step_wall_s[n_warm_steps:]
+    spans.disable()
+    step_walls = [(r["end_ns"] - r["start_ns"]) / 1e9 for r in spans.records()
+                  if r["name"] == "serve.fuser.step"]
     ttft_ms = float(np.median(ttft_s)) * 1e3 if ttft_s else 0.0
     decode_step_p99_ms = (float(np.percentile(step_walls, 99)) * 1e3
                           if step_walls else 0.0)

@@ -2,7 +2,7 @@
 pool + GEN-FUSER) and serve MixInstruct-style queries.
 
     PYTHONPATH=src python -m repro.launch.serve --budget 0.2 --n 16 \
-        [--policy modi] [--train-steps 300] [--online]
+        [--policy modi] [--train-steps 300] [--online] [--trace-spans PATH]
 
 ``build_stack`` trains (or randomly inits, for a pipeline demo) the
 scorer/fuser/predictor; ``main`` composes the layered serving stack:
@@ -45,6 +45,7 @@ from repro.serve import (
     RequestShed,
     Scheduler,
     requests_from_records,
+    spans,
 )
 from repro.train import repeat_batches, train
 import jax.numpy as jnp
@@ -190,9 +191,23 @@ def main():
     ap.add_argument("--prefill-chunk", type=int, default=None,
                     help="max rows per prefill call on the streaming path "
                          "(bounds how long a prompt burst can stall joins)")
+    ap.add_argument("--trace-spans", type=str, default=None, metavar="PATH",
+                    help="record the serving path's spans and write them to "
+                         "PATH as JSON lines at exit (README, \"Spans\")")
     args = ap.parse_args()
     enable_compile_cache()
+    if args.trace_spans:
+        spans.enable()
+    try:
+        serve(args)
+    finally:
+        if args.trace_spans:
+            n = spans.dump(args.trace_spans)
+            print(f"wrote {n} span records to {args.trace_spans}")
 
+
+def serve(args) -> None:
+    """Build the stack and serve ``args.n`` queries as ``main`` parsed them."""
     recs, scorer, scorer_p, fuser, fuser_p, predictor, pred_p = build_stack(
         args.train_steps, args.seed
     )

@@ -33,7 +33,6 @@ from __future__ import annotations
 import bisect
 import dataclasses
 import threading
-import time
 from collections import deque
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
@@ -44,6 +43,7 @@ import numpy as np
 from repro.data.tokenizer import TOKENIZER
 from repro.models.encdec import EncDecLM
 from repro.models.transformer import DecoderLM
+from repro.serve import spans
 from repro.serve.generate import (
     decoder_generate_with_cache,
     encdec_decode_step,
@@ -416,8 +416,6 @@ class StreamingEncDecBatcher:
         self._built = 0
         self.stats = {"prefills": 0, "joins": 0, "steps": 0, "rows": 0,
                       "evicted": 0, "padded_rows": 0}
-        # wall time per decode step, for time-to-first-token / per-step p99
-        self.step_wall_s: List[float] = []
 
     # -- compile accounting ---------------------------------------------
     @property
@@ -552,8 +550,10 @@ class StreamingEncDecBatcher:
         padded[:size] = enc
         if jb > size:
             padded[size:] = padded[0]  # replicate a real row (independence)
-        tok0, done0_dev, cache0 = self._prefill(jb)(self.params,
-                                                    jnp.asarray(padded))
+        with spans.span("serve.fuser.prefill", rows=size):
+            tok0, done0_dev, cache0 = self._prefill(jb)(self.params,
+                                                        jnp.asarray(padded))
+            done0 = np.asarray(done0_dev)
         self.stats["prefills"] += 1
         self.stats["rows"] += size
         self.stats["padded_rows"] += jb - size
@@ -569,7 +569,7 @@ class StreamingEncDecBatcher:
                 else None,
             ))
         self._pending.append(_JoinGroup(
-            size=size, jb=jb, tok0=tok0, done0=np.asarray(done0_dev),
+            size=size, jb=jb, tok0=tok0, done0=done0,
             done0_dev=done0_dev, cache=cache0, rows=rows))
 
     def _admit_pending(self) -> None:
@@ -583,9 +583,10 @@ class StreamingEncDecBatcher:
             del self._free[:g.size]
             idx = np.full((g.jb,), self.capacity, np.int32)  # padding -> OOB
             idx[:g.size] = slots
-            self._tok, self._pos, self._done, self._cache = self._join(g.jb)(
-                self._tok, self._pos, self._done, self._cache,
-                jnp.asarray(idx), g.tok0, g.done0_dev, g.cache)
+            with spans.span("serve.fuser.join", rows=g.size):
+                self._tok, self._pos, self._done, self._cache = self._join(g.jb)(
+                    self._tok, self._pos, self._done, self._cache,
+                    jnp.asarray(idx), g.tok0, g.done0_dev, g.cache)
             self.stats["joins"] += 1
             for slot, row, finished in zip(slots, g.rows, g.done0[:g.size]):
                 if finished or row.cap <= 0:
@@ -610,36 +611,45 @@ class StreamingEncDecBatcher:
             try:
                 while steps is None or executed < steps:
                     self._admit_pending()
-                    if all(r is None for r in self._rows):
+                    live = sum(r is not None for r in self._rows)
+                    if not live:
                         break
-                    t0 = time.perf_counter()
-                    emit, self._tok, self._pos, self._done, self._cache = (
-                        self._step()(self.params, self._tok, self._pos,
-                                     self._done, self._cache))
-                    emit_h = np.asarray(emit)
-                    done_h = np.asarray(self._done)
-                    self.step_wall_s.append(time.perf_counter() - t0)
-                    self.stats["steps"] += 1
+                    with spans.span("serve.fuser.step", rows=live):
+                        self._step_once()
                     executed += 1
-                    for slot in range(self.capacity):
-                        row = self._rows[slot]
-                        if row is None:
-                            continue
-                        row.tokens.append(int(emit_h[slot]))
-                        if row.on_token is not None:
-                            row.on_token(list(row.tokens))
-                        if done_h[slot] or len(row.tokens) >= row.cap:
-                            # leave: every later emission would be pad, or
-                            # the row's budget is spent — final text is
-                            # already byte-complete
-                            self._rows[slot] = None
-                            bisect.insort(self._free, slot)
-                            self.stats["evicted"] += 1
-                            row.on_done(list(row.tokens))
             except Exception as exc:
                 self._fail_all(exc)
                 raise
         return executed
+
+    def _step_once(self) -> None:
+        """One decode step over every slot: the jitted call (``launch``),
+        the host reads of ``emit`` and ``done`` (``read``), then each live
+        row's token and callbacks (``emit``)."""
+        with spans.span("serve.fuser.step.launch"):
+            emit, self._tok, self._pos, self._done, self._cache = (
+                self._step()(self.params, self._tok, self._pos,
+                             self._done, self._cache))
+        with spans.span("serve.fuser.step.read"):
+            emit_h = np.asarray(emit)
+            done_h = np.asarray(self._done)
+        self.stats["steps"] += 1
+        with spans.span("serve.fuser.step.emit"):
+            for slot in range(self.capacity):
+                row = self._rows[slot]
+                if row is None:
+                    continue
+                row.tokens.append(int(emit_h[slot]))
+                if row.on_token is not None:
+                    row.on_token(list(row.tokens))
+                if done_h[slot] or len(row.tokens) >= row.cap:
+                    # leave: every later emission would be pad, or the
+                    # row's budget is spent — final text is already
+                    # byte-complete
+                    self._rows[slot] = None
+                    bisect.insort(self._free, slot)
+                    self.stats["evicted"] += 1
+                    row.on_done(list(row.tokens))
 
     def _fail_all(self, exc: BaseException) -> None:
         rows = [r for r in self._rows if r is not None]

@@ -44,6 +44,7 @@ from repro.core.selector import SelectionPolicy, make_policy, realized_cost_frac
 from repro.data.mixinstruct import PoolMemberSpec, Record, query_cost_matrix
 from repro.data.tokenizer import TOKENIZER
 from repro.models.encdec import EncDecLM
+from repro.serve import spans
 from repro.serve.api import EnsembleRequest, EnsembleResponse, requests_from_records
 from repro.serve.backends import (
     GenerationCall,
@@ -78,9 +79,11 @@ class _BatchPlan:
     dropped: frozenset
     max_new_per_row: List[int]
     member_out: List[List[Optional[str]]]
-    predict_s: float
-    select_s: float
-    generate_s: float
+    start_ns: int  # perf_counter_ns at the batch's start: timing's total_s
+    # the stage spans, whose durations fill EnsembleResponse.timing
+    predict: spans.Span
+    select: spans.Span
+    members: spans.Span
 
 
 @dataclasses.dataclass
@@ -182,7 +185,10 @@ class EnsembleServer:
     # ------------------------------------------------------------------
     def predict_quality(self, queries: List[str]) -> np.ndarray:
         toks = TOKENIZER.batch_encode(queries, self.max_query_len, cls=True)
-        return np.asarray(self.predictor.apply(self.predictor_params, jnp.asarray(toks)))
+        with spans.span("serve.predict.apply"):
+            r_hat = self.predictor.apply(self.predictor_params, jnp.asarray(toks))
+        with spans.span("serve.predict.read"):
+            return np.asarray(r_hat)
 
     # ------------------------------------------------------------------
     def _policy_key(self, req: EnsembleRequest) -> Tuple:
@@ -246,17 +252,17 @@ class EnsembleServer:
                 bool(masked_members)
                 and isinstance(getattr(policy, "eps", None), EpsilonConstraint)
             )
+            cols = alive if resolve_masked else slice(None)
+            with spans.span("serve.select.solve", rows=len(rows)):
+                picked = policy.select(jnp.asarray(r_hat[rows][:, cols]),
+                                       jnp.asarray(costs[rows][:, cols]))
+            with spans.span("serve.select.read"):
+                picked = np.asarray(picked)
             if resolve_masked:
-                picked = np.asarray(policy.select(
-                    jnp.asarray(r_hat[rows][:, alive]),
-                    jnp.asarray(costs[rows][:, alive]),
-                ))
                 sub = np.zeros((len(rows), n), bool)
                 sub[:, alive] = picked
             else:
-                sub = np.asarray(
-                    policy.select(jnp.asarray(r_hat[rows]), jnp.asarray(costs[rows]))
-                )
+                sub = picked
             for local, i in enumerate(rows):
                 mask[i] = sub[local]
                 names[i] = policy.name
@@ -344,30 +350,31 @@ class EnsembleServer:
         """Encoder tokens [B, max_fusion_len] for the GEN-FUSER — shared by
         the batch-boundary and streaming fusion paths, so both decode the
         very same prompt."""
-        b, n = mask.shape
-        # member texts are pre-truncated to their row's max_new cap; the
-        # fusion-side cap only narrows further if explicitly configured
-        cap = max_new if self.max_member_tokens is None else self.max_member_tokens
-        flat = [
-            (i, j, text)
-            for i, row in enumerate(member_out)
-            for j, text in enumerate(row)
-            if text is not None
-        ]
-        resp_tokens = np.full((b, n, cap), TOKENIZER.pad_id, np.int32)
-        if flat:
-            # one batched tokenizer call over flat index arrays instead of a
-            # [B, N] Python grid of encode+assign steps
-            ii = np.fromiter((f[0] for f in flat), np.intp, len(flat))
-            jj = np.fromiter((f[1] for f in flat), np.intp, len(flat))
-            resp_tokens[ii, jj] = TOKENIZER.pad_batch(
-                [TOKENIZER.encode(f[2]) for f in flat], cap
+        with spans.span("serve.fusion_inputs", rows=len(queries)):
+            b, n = mask.shape
+            # member texts are pre-truncated to their row's max_new cap; the
+            # fusion-side cap only narrows further if explicitly configured
+            cap = max_new if self.max_member_tokens is None else self.max_member_tokens
+            flat = [
+                (i, j, text)
+                for i, row in enumerate(member_out)
+                for j, text in enumerate(row)
+                if text is not None
+            ]
+            resp_tokens = np.full((b, n, cap), TOKENIZER.pad_id, np.int32)
+            if flat:
+                # one batched tokenizer call over flat index arrays instead of a
+                # [B, N] Python grid of encode+assign steps
+                ii = np.fromiter((f[0] for f in flat), np.intp, len(flat))
+                jj = np.fromiter((f[1] for f in flat), np.intp, len(flat))
+                resp_tokens[ii, jj] = TOKENIZER.pad_batch(
+                    [TOKENIZER.encode(f[2]) for f in flat], cap
+                )
+            q_tokens = TOKENIZER.batch_encode(queries, self.max_query_len)
+            return build_fusion_batch(
+                q_tokens, resp_tokens, mask, TOKENIZER.sep_id, self.max_fusion_len,
+                TOKENIZER.pad_id,
             )
-        q_tokens = TOKENIZER.batch_encode(queries, self.max_query_len)
-        return build_fusion_batch(
-            q_tokens, resp_tokens, mask, TOKENIZER.sep_id, self.max_fusion_len,
-            TOKENIZER.pad_id,
-        )
 
     def _fuse(self, queries: List[str], member_out: List[List[Optional[str]]],
               mask: np.ndarray, max_new: int) -> np.ndarray:
@@ -398,93 +405,92 @@ class EnsembleServer:
         full-ensemble cost instead of carrying dead members' costs."""
         if not requests:
             return []
-        t_start = time.perf_counter()
         plan = self._plan_batch(requests, exclude_members, masked_members)
 
         max_new = max(plan.max_new_per_row)
-        t0 = time.perf_counter()
-        fused = self._fuse(plan.queries, plan.member_out, plan.mask, max_new)
-        t_fuse = time.perf_counter() - t0
+        with spans.span("serve.fuse") as fuse:
+            fused = self._fuse(plan.queries, plan.member_out, plan.mask, max_new)
 
         row_tokens = [fused[i, :plan.max_new_per_row[i]]
                       for i in range(len(requests))]
-        return self._settle(plan, row_tokens, t_start, t_fuse)
+        return self._settle(plan, row_tokens, fuse)
 
     def _plan_batch(self, requests: List[EnsembleRequest],
                     exclude_members: frozenset,
                     masked_members: frozenset) -> _BatchPlan:
         """Pre-fusion pipeline (predict → select → member generation),
         shared verbatim by the batch-boundary and streaming paths."""
+        start_ns = time.perf_counter_ns()
         records = [req.resolve_record() for req in requests]
         queries = [r.query for r in records]
 
-        t0 = time.perf_counter()
-        r_hat = self.predict_quality(queries)
-        t_predict = time.perf_counter() - t0
+        with spans.span("serve.predict", rows=len(queries)) as predict:
+            r_hat = self.predict_quality(queries)
 
         costs = query_cost_matrix(self.pool, records)
-        t0 = time.perf_counter()
         masked = frozenset(masked_members)
-        mask, policy_names = self._select(requests, r_hat, costs,
-                                          masked_members=masked)
         dropped = frozenset(exclude_members) | masked
-        if dropped:
-            mask = self._apply_exclusions(mask, costs, dropped)
-        t_select = time.perf_counter() - t0
+        with spans.span("serve.select", rows=len(queries)) as select:
+            mask, policy_names = self._select(requests, r_hat, costs,
+                                              masked_members=masked)
+            if dropped:
+                mask = self._apply_exclusions(mask, costs, dropped)
 
         max_new_per_row = [
             self.max_new_tokens if req.max_new_tokens is None else req.max_new_tokens
             for req in requests
         ]
-        t0 = time.perf_counter()
-        member_out = self._generate_members(records, mask, max_new_per_row)
-        t_generate = time.perf_counter() - t0
+        with spans.span("serve.members", rows=len(queries)) as members:
+            member_out = self._generate_members(records, mask, max_new_per_row)
         return _BatchPlan(
             records=records, queries=queries, r_hat=r_hat, costs=costs,
             mask=mask, policy_names=policy_names, dropped=dropped,
             max_new_per_row=max_new_per_row, member_out=member_out,
-            predict_s=t_predict, select_s=t_select, generate_s=t_generate,
+            start_ns=start_ns, predict=predict, select=select, members=members,
         )
 
     def _settle(self, plan: _BatchPlan, row_tokens: Sequence,
-                t_start: float, t_fuse: float) -> List[EnsembleResponse]:
+                fuse: spans.Span) -> List[EnsembleResponse]:
         """Cost accounting + response assembly over per-row fused tokens
         (a ``[row_new]`` slice from the batch path, or the exact emitted
-        sequence from the streaming path — both decode to the same text)."""
-        mask, costs, dropped = plan.mask, plan.costs, plan.dropped
-        frac = np.asarray(realized_cost_fraction(jnp.asarray(mask), jnp.asarray(costs)))
-        realized = np.sum(np.where(mask, costs, 0.0), axis=1)
-        # full-ensemble cost over the servable members only — the base a
-        # degraded batch settles against (ε re-targeted the survivors)
-        servable = np.asarray([j not in dropped for j in range(costs.shape[1])])
-        survivor_cost = np.sum(np.where(servable, costs, 0.0), axis=1)
-        total = time.perf_counter() - t_start
-        timing = {
-            "predict_s": plan.predict_s, "select_s": plan.select_s,
-            "generate_s": plan.generate_s, "fuse_s": t_fuse, "total_s": total,
-        }
+        sequence from the streaming path — both decode to the same text).
+        ``timing`` holds the stage spans' durations, and ``total_s`` the
+        batch's from its start to its cost accounting."""
+        with spans.span("serve.settle", rows=len(plan.records)):
+            mask, costs, dropped = plan.mask, plan.costs, plan.dropped
+            frac = np.asarray(realized_cost_fraction(jnp.asarray(mask), jnp.asarray(costs)))
+            realized = np.sum(np.where(mask, costs, 0.0), axis=1)
+            # full-ensemble cost over the servable members only — the base a
+            # degraded batch settles against (ε re-targeted the survivors)
+            servable = np.asarray([j not in dropped for j in range(costs.shape[1])])
+            survivor_cost = np.sum(np.where(servable, costs, 0.0), axis=1)
+            timing = {
+                "predict_s": plan.predict.seconds, "select_s": plan.select.seconds,
+                "generate_s": plan.members.seconds, "fuse_s": fuse.seconds,
+                "total_s": (time.perf_counter_ns() - plan.start_ns) / 1e9,
+            }
 
-        self.stats["queries"] += len(plan.records)
-        self.stats["batches"] += 1
-        self.stats["flops"] += float(realized.sum())
-        self.stats["full_flops"] += float(np.sum(costs))
+            self.stats["queries"] += len(plan.records)
+            self.stats["batches"] += 1
+            self.stats["flops"] += float(realized.sum())
+            self.stats["full_flops"] += float(np.sum(costs))
 
-        responses = []
-        for i in range(len(plan.records)):
-            responses.append(EnsembleResponse(
-                text=TOKENIZER.decode(row_tokens[i]),
-                member_texts=plan.member_out[i],
-                mask=mask[i],
-                realized_cost=float(realized[i]),
-                cost_fraction=float(frac[i]),
-                predicted_quality=plan.r_hat[i],
-                policy_name=plan.policy_names[i],
-                timing=dict(timing),
-                degraded=bool(dropped),
-                missing_members=tuple(sorted(dropped)),
-                survivor_cost=float(survivor_cost[i]),
-            ))
-        return responses
+            responses = []
+            for i in range(len(plan.records)):
+                responses.append(EnsembleResponse(
+                    text=TOKENIZER.decode(row_tokens[i]),
+                    member_texts=plan.member_out[i],
+                    mask=mask[i],
+                    realized_cost=float(realized[i]),
+                    cost_fraction=float(frac[i]),
+                    predicted_quality=plan.r_hat[i],
+                    policy_name=plan.policy_names[i],
+                    timing=dict(timing),
+                    degraded=bool(dropped),
+                    missing_members=tuple(sorted(dropped)),
+                    survivor_cost=float(survivor_cost[i]),
+                ))
+            return responses
 
     # ------------------------------------------------------------------
     def stream_fuser(self, capacity: int = 8,
@@ -526,40 +532,37 @@ class EnsembleServer:
         degrade to one coarse event rather than an error."""
         if not requests:
             return []
-        t_start = time.perf_counter()
         plan = self._plan_batch(requests, exclude_members, masked_members)
         max_new = max(plan.max_new_per_row)
 
         fuser = (self.stream_fuser(capacity, prefill_chunk)
                  if self.fuser_dispatch is not None else None)
         if fuser is None or max_new > fuser.max_new_cap:
-            t0 = time.perf_counter()
-            fused = self._fuse(plan.queries, plan.member_out, plan.mask, max_new)
-            t_fuse = time.perf_counter() - t0
+            with spans.span("serve.fuse") as fuse:
+                fused = self._fuse(plan.queries, plan.member_out, plan.mask, max_new)
             row_tokens = [fused[i, :plan.max_new_per_row[i]]
                           for i in range(len(requests))]
             if on_token is not None:
                 for i, toks in enumerate(row_tokens):
                     on_token(i, [int(t) for t in toks])
-            return self._settle(plan, row_tokens, t_start, t_fuse)
+            return self._settle(plan, row_tokens, fuse)
 
-        t0 = time.perf_counter()
-        fuse_in = self._fusion_inputs(plan.queries, plan.member_out,
-                                      plan.mask, max_new)
         done_tokens: Dict[int, List[int]] = {}
         errors: List[BaseException] = []
-        fuser.submit(
-            fuse_in, list(plan.max_new_per_row),
-            on_token=on_token,
-            on_done=lambda i, toks: done_tokens.__setitem__(i, toks),
-            on_error=lambda i, exc: errors.append(exc),
-        )
-        fuser.pump()
+        with spans.span("serve.fuse") as fuse:
+            fuse_in = self._fusion_inputs(plan.queries, plan.member_out,
+                                          plan.mask, max_new)
+            fuser.submit(
+                fuse_in, list(plan.max_new_per_row),
+                on_token=on_token,
+                on_done=lambda i, toks: done_tokens.__setitem__(i, toks),
+                on_error=lambda i, exc: errors.append(exc),
+            )
+            fuser.pump()
         if errors:
             raise errors[0]
-        t_fuse = time.perf_counter() - t0
         row_tokens = [done_tokens[i] for i in range(len(requests))]
-        return self._settle(plan, row_tokens, t_start, t_fuse)
+        return self._settle(plan, row_tokens, fuse)
 
     # ------------------------------------------------------------------
     def serve(self, records: List[Record],

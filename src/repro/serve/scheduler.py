@@ -73,11 +73,13 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import itertools
 import threading
 import time
 from typing import Dict, Iterator, List, Optional, Tuple
 
 from repro.data.tokenizer import TOKENIZER
+from repro.serve import spans
 from repro.serve.api import EnsembleRequest, EnsembleResponse, StreamEvent
 from repro.serve.backends import HostFailure, MemberFailure
 from repro.serve.cluster.worker import DispatchWorker, InboxFull
@@ -113,7 +115,10 @@ class ResponseFuture:
         self._stream_cv = threading.Condition()
         self._stream_events: List[StreamEvent] = []
         self.deadline_missed = False  # dispatched after its deadline tick
-        self.ttft_s: Optional[float] = None  # wall s to first streamed token
+        self.ttft_s: Optional[float] = None  # wall s, submit to first streamed token
+        # submit -> resolution; its record (tracer on) carries the batch
+        # that served it, when that service started, and the first token
+        self._span = spans.start("serve.request", req=seq)
 
     def done(self) -> bool:
         return self._done
@@ -200,6 +205,7 @@ class ResponseFuture:
         with self._resolve_lock:
             self._response = response
             self._done = True
+            self._span.end()
             self._resolved.set()
         with self._stream_cv:
             self._stream_cv.notify_all()
@@ -208,6 +214,7 @@ class ResponseFuture:
         with self._resolve_lock:
             self._error = error
             self._done = True
+            self._span.end()
             self._resolved.set()
         with self._stream_cv:
             self._stream_cv.notify_all()
@@ -280,6 +287,7 @@ class _BatchJob:
     batch: List[_Pending]
     dispatch_tick: int
     events: List[dict]
+    batch_id: int  # dispatch order; joins a request's span to its batch's
 
 
 class Scheduler:
@@ -328,6 +336,7 @@ class Scheduler:
         self._events: List = []
         self._lock = threading.Lock()
         self._service_ewma: Optional[float] = None  # inter-dispatch gap ticks
+        self._batch_ids = itertools.count()
         self._last_dispatch_tick: Optional[int] = None
         self._worker: Optional[DispatchWorker] = None
         if not sync:
@@ -662,7 +671,8 @@ class Scheduler:
         batch = group[:take]
         members = set(id(p) for p in batch)
         self._queue = [p for p in self._queue if id(p) not in members]
-        job = _BatchJob(batch=batch, dispatch_tick=self.now, events=[])
+        job = _BatchJob(batch=batch, dispatch_tick=self.now, events=[],
+                        batch_id=next(self._batch_ids))
         if self.record_events:
             self._events.append(job.events)  # reserve the trace slot now
         if self._worker is None:
@@ -726,21 +736,33 @@ class Scheduler:
 
     def _stream_push(self, batch: List[_Pending], t0: float):
         """Row-indexed ``on_token`` fanning the engine's decode-step
-        emissions out to each row's future (plus TTFT capture)."""
+        emissions out to each row's future (plus TTFT capture, counted from
+        the request's submit; ``t0`` is when the batch's service began)."""
         def on_token(i: int, tokens: List[int]) -> None:
             fut = batch[i].future
             if fut.ttft_s is None:
-                fut.ttft_s = time.perf_counter() - t0
+                now = time.perf_counter_ns()
+                fut.ttft_s = (now - fut._span.start_ns) / 1e9
+                fut._span.set(first_token_ns=now)
             fut._push_stream(tokens)
             with self._lock:
                 self.stats["stream_tokens"] += 1
         return on_token
 
     def _serve_batch(self, job: _BatchJob) -> None:
-        """Serve one formed batch: the engine call plus hedged retries.
-        Runs inline (sync) or on the worker thread (async); every tick
-        stamp uses ``job.dispatch_tick``, so both modes write the same
-        trace."""
+        """Serve one formed batch inside its ``serve.batch`` span, stamping
+        each request's span with the batch and the service start.  Runs
+        inline (sync) or on the worker thread (async)."""
+        with spans.span("serve.batch", batch=job.batch_id,
+                        rows=len(job.batch)) as service:
+            for p in job.batch:
+                p.future._span.set(batch=job.batch_id, service_ns=service.start_ns)
+            self._serve_job(job)
+
+    def _serve_job(self, job: _BatchJob) -> None:
+        """The engine call plus hedged retries, then settlement.  Every
+        tick stamp uses ``job.dispatch_tick``, so both modes write the
+        same trace."""
         batch, tick = job.batch, job.dispatch_tick
         exclude: frozenset = frozenset()
         # pre-mask members already known dead (a cluster backend's plan

@@ -42,6 +42,7 @@ from repro.serve import (
     greedy_generate_encdec,
     preset_scenarios,
     requests_from_records,
+    spans,
 )
 
 pytestmark = pytest.mark.streaming
@@ -187,6 +188,44 @@ def test_stream_prefix_stability_and_final_equality(stack):
             assert final.text.startswith(ev.text)
         assert f.ttft_s is not None and f.ttft_s >= 0.0
     assert sched.stats["stream_tokens"] > 0
+
+
+def test_ttft_counts_queue_wait_and_each_decode_step_has_one_span(stack):
+    """TTFT runs from submit, so it holds the request's queue wait; every
+    served batch records one ``serve.fuser.step`` span per decode step."""
+    server = _server(stack, budget=0.2)
+    sched = Scheduler(server, max_batch_size=4, stream=True, stream_capacity=4,
+                      sync=False)
+    spans.enable()
+    try:
+        futs = [sched.submit(r) for r in requests_from_records(RECORDS[:8])]
+        sched.flush()
+        streamed = {f.seq: [ev for ev in f.stream(timeout=300) if not ev.final]
+                    for f in futs}
+        sched.close()
+    finally:
+        spans.disable()
+    recs = spans.records()
+    by_id = {r["id"]: r for r in recs}
+    requests = {r["attrs"]["req"]: r for r in recs if r["name"] == "serve.request"}
+    for f in futs:
+        req = requests[f.seq]
+        queue_wait_ns = req["attrs"]["service_ns"] - req["start_ns"]
+        assert queue_wait_ns >= 0 and f.ttft_s * 1e9 >= queue_wait_ns
+    steps_by_batch = {}
+    for r in recs:
+        if r["name"] == "serve.fuser.step":
+            up = r
+            while up["name"] != "serve.batch":
+                up = by_id[up["parent"]]
+            steps_by_batch[up["attrs"]["batch"]] = steps_by_batch.get(up["attrs"]["batch"], 0) + 1
+    assert len(steps_by_batch) == 2
+    for b, n_steps in steps_by_batch.items():
+        rows = [f.seq for f in futs if requests[f.seq]["attrs"]["batch"] == b]
+        # the batch drains before the next is served: it decodes as many
+        # steps as its longest row streamed tokens
+        assert n_steps == max(len(streamed[seq]) for seq in rows)
+    assert sum(steps_by_batch.values()) == server.stream_fuser().stats["steps"]
 
 
 def test_streaming_preset_sync_async_byte_equivalence(stack):
