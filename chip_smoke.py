@@ -269,14 +269,19 @@ def phase_serve(seed: int):
     server.stream_fuser(MAX_BATCH).warm(rungs)
     warm_s = time.perf_counter() - t0
     compiles = server.generate_compiles()
+    predict_programs = server.predict_compiles
     _say("warm", f"rungs {rungs}: {warm_s:.1f} s compiling and warming "
-         f"{compiles['total']} generate programs (smoke run, not a benchmark)")
+         f"{compiles['total']} generate programs and {predict_programs} predictor "
+         "programs (smoke run, not a benchmark)")
 
     records, reqs = _requests(seed)
     batch, batch_groups = _serve(server, reqs, stream=False)
     stream, _ = _serve(server, reqs, stream=True)
     _check(server.generate_compiles() == compiles,
            f"generate compiled after warm-up: {compiles} -> {server.generate_compiles()}")
+    _check(server.predict_compiles == predict_programs,
+           f"predictor compiled after warm-up: {predict_programs} -> "
+           f"{server.predict_compiles}")
     for i, (a, b) in enumerate(zip(batch, stream)):
         _check(a.text == b.text, f"request {i}: streamed text differs from batch path")
         _check((a.mask == b.mask).all(), f"request {i}: streamed mask differs")
@@ -381,8 +386,10 @@ def phase_cluster(seed: int) -> None:
     t0 = time.perf_counter()
     server.warm([(MAX_BATCH, server.max_new_tokens)])
     compiles = server.generate_compiles()
+    predict_programs = server.predict_compiles
     _say("warm", f"{time.perf_counter() - t0:.1f} s compiling and warming "
-         f"{compiles['total']} generate programs (smoke run, not a benchmark)")
+         f"{compiles['total']} generate programs and {predict_programs} predictor "
+         "programs (smoke run, not a benchmark)")
 
     _, reqs = _requests(seed)
     reqs = reqs[:N_REQUESTS]
@@ -400,6 +407,7 @@ def phase_cluster(seed: int) -> None:
              f"{router.stats['dispatches']} member dispatches, {wall:.2f} s wall "
              "(smoke run, not a benchmark)")
     _check(server.generate_compiles() == compiles, "generate compiled after warm-up")
+    _check(server.predict_compiles == predict_programs, "predictor compiled after warm-up")
     for i, (a, b) in enumerate(zip(results[True], results[False])):
         _check(a.text == b.text and a.member_texts == b.member_texts,
                f"request {i}: fan-out and sequential routing differ")

@@ -34,6 +34,7 @@ import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -121,6 +122,11 @@ class EnsembleServer:
         self.policy = policy
         self.predictor = predictor
         self.predictor_params = predictor_params
+        # the predictor as one jitted program per batch rung: its jit cache
+        # keys on the padded token shape, so each rung compiles once (on
+        # first use or in warm) and every later batch reuses it.  The
+        # weights are an argument, never baked-in constants.
+        self._predict_fn = jax.jit(predictor.apply)
         self.fuser = fuser
         self.fuser_params = fuser_params
         ladder = bucket_ladder or BucketLadder()
@@ -159,10 +165,13 @@ class EnsembleServer:
 
     # ------------------------------------------------------------------
     def warm(self, shapes: Sequence[Tuple[int, int]]) -> None:
-        """Pre-compile generate buckets for (batch, max_new) shapes so the
-        first admission micro-batches don't pay the compile.  Backends
-        opt in by exposing ``warm(shapes)`` (optional protocol hook — see
-        LiveLMBackend); backends without one have nothing to compile."""
+        """Pre-compile generate buckets for (batch, max_new) shapes, and the
+        predictor's program for each batch's rung, so the first admission
+        micro-batches don't pay the compile.  Backends opt in by exposing
+        ``warm(shapes)`` (optional protocol hook — see LiveLMBackend);
+        backends without one have nothing to compile."""
+        for rung in sorted({self.bucket_ladder.batch_bucket(b) for b, _ in shapes}):
+            self.predict_quality([""] * rung)
         if self.fuser_dispatch is not None:
             self.fuser_dispatch.warm(
                 [(b, self.max_fusion_len, n) for b, n in shapes]
@@ -182,13 +191,25 @@ class EnsembleServer:
         return {"fuser": fuser, "members": members, "stream": stream,
                 "total": fuser + members + stream}
 
+    @property
+    def predict_compiles(self) -> int:
+        """Predictor programs built: one per batch rung served or warmed."""
+        return self._predict_fn._cache_size()
+
     # ------------------------------------------------------------------
     def predict_quality(self, queries: List[str]) -> np.ndarray:
+        """[B, N] predicted quality.  Rows pad up to the batch's rung with
+        pad-only queries; the predictor has no cross-row operation, so the
+        padding cannot reach real rows, and is sliced off."""
         toks = TOKENIZER.batch_encode(queries, self.max_query_len, cls=True)
+        b = len(toks)
+        padded = np.full((self.bucket_ladder.batch_bucket(b), toks.shape[1]),
+                         TOKENIZER.pad_id, np.int32)
+        padded[:b] = toks
         with spans.span("serve.predict.apply"):
-            r_hat = self.predictor.apply(self.predictor_params, jnp.asarray(toks))
+            r_hat = self._predict_fn(self.predictor_params, jnp.asarray(padded))
         with spans.span("serve.predict.read"):
-            return np.asarray(r_hat)
+            return np.asarray(r_hat)[:b]
 
     # ------------------------------------------------------------------
     def _policy_key(self, req: EnsembleRequest) -> Tuple:
@@ -424,7 +445,8 @@ class EnsembleServer:
         records = [req.resolve_record() for req in requests]
         queries = [r.query for r in records]
 
-        with spans.span("serve.predict", rows=len(queries)) as predict:
+        with spans.span("serve.predict", rows=len(queries),
+                        rung=self.bucket_ladder.batch_bucket(len(queries))) as predict:
             r_hat = self.predict_quality(queries)
 
         costs = query_cost_matrix(self.pool, records)

@@ -22,6 +22,7 @@ from repro.serve import (
     greedy_generate,
     greedy_generate_encdec,
     requests_from_records,
+    spans,
 )
 
 
@@ -192,8 +193,74 @@ def test_server_warm_shapes_precompile(stack):
         warm_shapes=[(2, 32)],
     )
     assert server.generate_compiles()["total"] == 1
+    assert server.predict_compiles == 1  # warm also built the predictor's rung
     server.serve_requests(requests_from_records(generate_dataset(2, seed=5)))
     assert server.generate_compiles()["total"] == 1  # bucket already warm
+    assert server.predict_compiles == 1
+
+
+# ---------------------------------------------------------------------------
+# The predictor: one jitted program per batch rung
+# ---------------------------------------------------------------------------
+
+
+def test_predictor_compiles_once_per_rung(stack):
+    """Batches of 8, 8, 3 and 4 rows build two predictor programs (rungs 8
+    and 4), and a rung's repeated call fires no JAX compile event."""
+    pred, pp, fuser, fp = stack
+    server = EnsembleServer(DEFAULT_POOL, make_policy("modi", budget=0.2),
+                            pred, pp, fuser, fp)
+    queries = [r.query for r in generate_dataset(8, seed=23)]
+    events = []
+
+    def on_event(event, **kwargs):
+        if event in spans.COUNTED_EVENTS:
+            events.append(event)
+
+    def on_duration(event, duration, **kwargs):
+        if event in spans.TIMED_EVENTS:
+            events.append(event)
+
+    fired, counts = [], []
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        for b in (8, 8, 3, 4):
+            del events[:]
+            assert server.predict_quality(queries[:b]).shape == (b, len(DEFAULT_POOL))
+            fired.append(list(events))
+            counts.append(server.predict_compiles)
+    finally:
+        jax.monitoring.unregister_event_listener(on_event)
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert counts == [1, 1, 2, 2]
+    assert fired[1] == [] and fired[3] == []  # the rung's program is reused
+    assert server.generate_compiles()["total"] == 0  # the generate paths' count
+
+
+@pytest.mark.parametrize("b", [1, 3, 8])
+def test_predict_quality_matches_eager_apply(stack, b):
+    """Padding a batch to its rung leaves the real rows' scores as the
+    eager, unpadded predictor gives them, up to float32 rounding (the
+    compiled program fuses differently: scores near 0 differ by ~1e-7)."""
+    pred, pp, fuser, fp = stack
+    server = EnsembleServer(DEFAULT_POOL, make_policy("modi", budget=0.2),
+                            pred, pp, fuser, fp)
+    queries = [r.query for r in generate_dataset(b, seed=29)]
+    toks = TOKENIZER.batch_encode(queries, server.max_query_len, cls=True)
+    want = np.asarray(pred.apply(pp, jnp.asarray(toks)))
+    got = server.predict_quality(queries)
+    assert got.shape == want.shape == (b, len(DEFAULT_POOL))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_predictor_program_traces_highest_precision(stack):
+    pred, pp, fuser, fp = stack
+    server = EnsembleServer(DEFAULT_POOL, make_policy("modi", budget=0.2),
+                            pred, pp, fuser, fp)
+    tokens = jnp.zeros((8, server.max_query_len), jnp.int32)
+    text = server._predict_fn.lower(pp, tokens).as_text()
+    assert "dot_general" in text and "HIGHEST" in text
 
 
 # ---------------------------------------------------------------------------
